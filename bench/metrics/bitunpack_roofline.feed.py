@@ -1,0 +1,10 @@
+"""``bitunpack``'s share of its memory roofline: the bytes the unpack
+needs (``roofline.bitunpack_bytes``) over HBM bandwidth, against the
+traced time of the kernel's operations."""
+from bench.roofline import kernel_roofline
+
+OP = r"custom-call\(.*tpu_custom_call"  # the Pallas kernel, not the padding around it
+
+
+def read(ctx):
+    return kernel_roofline(ctx, "bitunpack", OP)

@@ -1,0 +1,10 @@
+"""``dict_decode``'s share of its memory roofline: the bytes the decode
+needs (``roofline.dict_decode_bytes``) over HBM bandwidth, against the
+traced time of the kernel's operations."""
+from bench.roofline import kernel_roofline
+
+OP = r"custom-call\(.*tpu_custom_call"  # the Pallas kernel, not the padding around it
+
+
+def read(ctx):
+    return kernel_roofline(ctx, "dict_decode", OP)
