@@ -248,11 +248,14 @@ SCENARIOS = [
 
 def _check_overhead(csv: Csv, root: str) -> None:
     """Disabled-tracer overhead < 2%: E events' worth of no-op ``if tr is
-    not None`` guards, measured directly, vs the disabled-run wall time."""
+    not None`` guards, plus the job's spans' worth of ``trace.span`` (each
+    still enters its profiler annotation), measured directly, vs the
+    disabled-run wall time."""
     t_dis, _ = timeit(lambda: _scn_fig1_where_job(root), repeat=2)
     with trace.tracing() as tr:
         _scn_fig1_where_job(root)
     n_events = len(tr.events())
+    n_spans = sum(1 for e in tr.events() if e[0] == "X")
     live = trace.live()  # tracing() exited -> None again
     assert live is None
     t0 = time.perf_counter()
@@ -260,6 +263,9 @@ def _check_overhead(csv: Csv, root: str) -> None:
     for _ in range(n_events):
         if live is not None:  # the exact guard the hot paths pay
             hits += 1
+    for _ in range(n_spans):
+        with trace.span("regress.span"):
+            pass
     t_guards = time.perf_counter() - t0
     assert hits == 0
     frac = t_guards / t_dis

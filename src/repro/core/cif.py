@@ -340,6 +340,14 @@ class _LazyReaders(dict):
         return r
 
 
+def _read_file(path: str, column: str) -> bytes:
+    """One column file, read whole, under a ``storage.read`` span."""
+    with trace.span("storage.read", column=column) as sp, open(path, "rb") as f:
+        raw = f.read()
+        sp.set_metadata(bytes=len(raw))
+    return raw
+
+
 class SplitReader:
     """RecordReader for one split-directory.
 
@@ -486,8 +494,7 @@ class SplitReader:
                 "split": self.split_id, "column": name, "attempt": a,
                 "host": host, "healed": healed,
             })
-        with open(opath if healed else path, "rb") as f:
-            raw = f.read()
+        raw = _read_file(opath if healed else path, name)
         if self._fault_plan is not None:
             raw = self._fault_plan.apply(
                 raw, host=host, split=self.split_id or 0, column=name,
@@ -521,8 +528,7 @@ class SplitReader:
         if self._policy is None and self._fault_plan is None:
             # no retry policy: plain open — still graceful typed errors and
             # lazy verification, but corruption raises instead of recovering
-            with open(path, "rb") as f:
-                raw = f.read()
+            raw = _read_file(path, name)
             return ColumnFileReader(
                 raw, typ, path=path, fail=self.fail, cache=self._cache
             )

@@ -703,7 +703,9 @@ class ColumnFileReader:
             raise CorruptFileError(
                 self.path, end, "checksum fields are not the file's tail"
             )
-        got = crc_of(ck.algo, raw[: self._body_start] + raw[body_end:end])
+        meta = raw[: self._body_start] + raw[body_end:end]
+        with trace.span("colfile.verify", bytes=len(meta)):
+            got = crc_of(ck.algo, meta)
         if got != ck.meta_crc:
             self._note_corruption()
             raise BlockCorruptionError(
@@ -721,7 +723,9 @@ class ColumnFileReader:
         if ck is None or not self._verify or bi in self._ck_ok:
             return
         a, b = self._spans[bi]
-        if crc_of(ck.algo, self.body[a:b]) == ck.block_crcs[bi]:
+        with trace.span("colfile.verify", bytes=b - a):
+            ok = crc_of(ck.algo, self.body[a:b]) == ck.block_crcs[bi]
+        if ok:
             self._ck_ok.add(bi)
             return
         self._note_corruption()

@@ -197,46 +197,44 @@ def run_job(
     t0 = time.perf_counter()
     tr = trace.live()
     batch_mode = map_batch_fn is not None or open_split_batches is not None
-    if batch_mode:
-        assert map_batch_fn is not None and open_split_batches is not None, (
-            "batch mode needs both open_split_batches and map_batch_fn"
-        )
-        assert map_fn is None and open_split is None, "pick ONE map-side mode"
-        if where is not None:
-            inner_open = open_split_batches
+    with trace.span("job.plan", splits=len(split_ids),
+                    mode="batches" if batch_mode else "records",
+                    where=where is not None):
+        if batch_mode:
+            assert map_batch_fn is not None and open_split_batches is not None, (
+                "batch mode needs both open_split_batches and map_batch_fn"
+            )
+            assert map_fn is None and open_split is None, "pick ONE map-side mode"
+            if where is not None:
+                inner_open = open_split_batches
 
-            def open_split_batches(split_id: int) -> Iterator[Any]:
-                for cb in inner_open(split_id):
-                    fb = cb.filter(where)
-                    if fb is not None and fb.n_rows:
-                        yield fb
-    else:
-        assert map_fn is not None and open_split is not None, (
-            "record mode needs both open_split and map_fn"
-        )
-        if where is not None:
-            inner_map = map_fn
+                def open_split_batches(split_id: int) -> Iterator[Any]:
+                    for cb in inner_open(split_id):
+                        fb = cb.filter(where)
+                        if fb is not None and fb.n_rows:
+                            yield fb
+        else:
+            assert map_fn is not None and open_split is not None, (
+                "record mode needs both open_split and map_fn"
+            )
+            if where is not None:
+                inner_map = map_fn
 
-            def map_fn(key: Any, rec: Any, emit: Callable[[Any, Any], None]) -> None:
-                if where.matches_record(rec):
-                    inner_map(key, rec, emit)
-    placement = placement or Placement(n_splits=len(split_ids), n_hosts=n_hosts)
-    start_dead = set(dead_hosts or ())
-    if fault_plan is not None:
-        start_dead |= fault_plan.start_dead()
-    wq = WorkQueue(placement, dead_hosts=start_dead)
-    if not wq.coverage_possible():
-        raise CoverageError("a split lost all replicas — job cannot run")
-    policy = failure_policy or (DEFAULT_POLICY if fault_plan is not None else None)
+                def map_fn(key: Any, rec: Any, emit: Callable[[Any, Any], None]) -> None:
+                    if where.matches_record(rec):
+                        inner_map(key, rec, emit)
+        placement = placement or Placement(n_splits=len(split_ids), n_hosts=n_hosts)
+        start_dead = set(dead_hosts or ())
+        if fault_plan is not None:
+            start_dead |= fault_plan.start_dead()
+        wq = WorkQueue(placement, dead_hosts=start_dead)
+        if not wq.coverage_possible():
+            raise CoverageError("a split lost all replicas — job cannot run")
+        policy = failure_policy or (DEFAULT_POLICY if fault_plan is not None else None)
 
-    live_hosts = [h for h in range(placement.n_hosts) if h not in start_dead]
+        live_hosts = [h for h in range(placement.n_hosts) if h not in start_dead]
 
     t_plan = time.perf_counter()
-    if tr is not None:
-        tr.complete("job.plan", int(t0 * 1e6), int(t_plan * 1e6),
-                    {"splits": len(split_ids),
-                     "mode": "batches" if batch_mode else "records",
-                     "where": where is not None})
 
     def run_split(sidx: int) -> Tuple[List[Tuple[Any, Any]], float]:
         split_id = split_ids[sidx]
@@ -300,14 +298,11 @@ def run_job(
         epoch = wq.epoch(sidx)
         try:
             with execution_epoch(epoch):
-                if tr is not None:
-                    # (split, epoch) executions are deterministic — epochs
-                    # bump on deterministic requeues, never on the race of
-                    # which worker ran them
-                    with tr.span("split",
-                                 {"split": split_ids[sidx], "epoch": epoch}):
-                        return run_split(sidx)
-                return run_split(sidx)
+                # (split, epoch) executions are deterministic — epochs
+                # bump on deterministic requeues, never on the race of
+                # which worker ran them
+                with trace.span("split", split=split_ids[sidx], epoch=epoch):
+                    return run_split(sidx)
         except (SplitRetryExhausted, CorruptFileError, OSError) as e:
             if policy is None or not wq.requeue(sidx, policy.max_reexecutions):
                 raise SplitUnserveableError(
@@ -371,60 +366,57 @@ def run_job(
     # hardware — more threads than cores only adds GIL/scheduler thrash in a
     # single-process simulated cluster.  Every live host's loop still runs.
     pool_size = min(n_workers, len(live_hosts), os.cpu_count() or n_workers)
-    if pool_size > 1:
-        with ThreadPoolExecutor(max_workers=pool_size) as pool:
-            for fut in [pool.submit(host_loop, h) for h in live_hosts]:
-                tasks.extend(fut.result())
-        drain(tasks)  # no-op unless a late death orphaned an in-flight split
-    else:
-        drain(tasks)
+    with trace.span("job.map", splits=len(split_ids)):
+        if pool_size > 1:
+            with ThreadPoolExecutor(max_workers=pool_size) as pool:
+                for fut in [pool.submit(host_loop, h) for h in live_hosts]:
+                    tasks.extend(fut.result())
+            drain(tasks)  # no-op unless a late death orphaned an in-flight split
+        else:
+            drain(tasks)
     assert len(tasks) == len(split_ids), "scheduler lost or duplicated a split"
     t_map_end = time.perf_counter()
-    if tr is not None:
-        tr.complete("job.map", int(t_plan * 1e6), int(t_map_end * 1e6),
-                    {"splits": len(split_ids)})
 
-    # deterministic fold: split order, stable partitioning
-    shuffle: List[Dict[Any, List[Any]]] = [defaultdict(list) for _ in range(n_reducers)]
-    map_time = 0.0
-    n_map_out = 0
-    host_of_split: Dict[int, int] = {}
-    remote_reads = 0
-    for sidx, h, local_out, dt in sorted(tasks, key=lambda t: t[0]):
-        host_of_split[split_ids[sidx]] = h
-        if not placement.is_local(sidx, h):
-            remote_reads += 1  # CPP makes this impossible; counted to prove it
-        map_time += dt
-        n_map_out += len(local_out)
-        if n_reducers == 1:
-            part = shuffle[0]
-            for k, v in local_out:
-                part[k].append(v)
-        else:
-            for k, v in local_out:
-                shuffle[stable_partition(k, n_reducers)][k].append(v)
+    # the fold between the map barrier and t_shuffle is shuffle work too
+    with trace.span("job.shuffle", reducers=n_reducers):
+        # deterministic fold: split order, stable partitioning
+        shuffle: List[Dict[Any, List[Any]]] = [defaultdict(list) for _ in range(n_reducers)]
+        map_time = 0.0
+        n_map_out = 0
+        host_of_split: Dict[int, int] = {}
+        remote_reads = 0
+        for sidx, h, local_out, dt in sorted(tasks, key=lambda t: t[0]):
+            host_of_split[split_ids[sidx]] = h
+            if not placement.is_local(sidx, h):
+                remote_reads += 1  # CPP makes this impossible; counted to prove it
+            map_time += dt
+            n_map_out += len(local_out)
+            if n_reducers == 1:
+                part = shuffle[0]
+                for k, v in local_out:
+                    part[k].append(v)
+            else:
+                for k, v in local_out:
+                    shuffle[stable_partition(k, n_reducers)][k].append(v)
 
-    t_shuffle = time.perf_counter()
-    # sort phase (keys sorted per reducer, as Hadoop does)
-    sorted_parts = [sorted(part.items(), key=lambda kv: repr(kv[0])) for part in shuffle]
+        t_shuffle = time.perf_counter()
+        # sort phase (keys sorted per reducer, as Hadoop does)
+        sorted_parts = [sorted(part.items(), key=lambda kv: repr(kv[0])) for part in shuffle]
     t_reduce = time.perf_counter()
 
     output: List[Tuple[Any, Any]] = []
     emit_r = lambda k, v: output.append((k, v))
-    if reduce_fn is None:
-        for part in sorted_parts:
-            output.extend((k, vs) for k, vs in part)
-    else:
-        for part in sorted_parts:
-            for k, vs in part:
-                reduce_fn(k, vs, emit_r)
+    with trace.span("job.reduce"):
+        if reduce_fn is None:
+            for part in sorted_parts:
+                output.extend((k, vs) for k, vs in part)
+        else:
+            for part in sorted_parts:
+                for k, vs in part:
+                    reduce_fn(k, vs, emit_r)
     t_end = time.perf_counter()
 
     if tr is not None:
-        # the fold between the map barrier and t_shuffle is shuffle work too
-        tr.complete("job.shuffle", int(t_map_end * 1e6), int(t_reduce * 1e6),
-                    {"reducers": n_reducers})
-        tr.complete("job.reduce", int(t_reduce * 1e6), int(t_end * 1e6))
         tr.counter("job.stats", {"splits_reexecuted": wq.reexecutions})
 
     if scan_stats is not None:

@@ -3,14 +3,30 @@
 The tracer is the observability backbone for the whole stack (PR 9):
 ``run_job`` phases, ``SplitReader`` fetch attempts and the PR-6/7 failure
 ladder, ``ColumnFileReader`` block decode / cache hits, and ``ServeEngine``
-admission all emit events here.  Design constraints, in order:
+admission all emit events here.
+
+Spans have two sinks and one emission path, ``span(name, **args)``:
+
+* **The profiler.**  Every span enters ``jax.profiler.TraceAnnotation``,
+  which records only while a profile is being captured, on the profiler's
+  own clock, so host spans (the token feed's ``feed.*``, ``split.*``,
+  ``storage.read``, ``colfile.verify``, ``decode.*``) line up with the
+  device operations on one timeline.  With no capture running an
+  annotation costs about a microsecond.
+* **The installed tracer.**  When ``live()`` is not None the same span is
+  also recorded there as an "X" event with the same args.
+  ``Tracer.span`` goes through the same path.
+
+Design constraints of the tracer, in order:
 
 * **Zero cost when disabled.**  The module-level active tracer defaults to
   a disabled singleton; ``live()`` returns ``None`` for it, so hot paths
   capture ``self._tr = trace.live()`` once at construction and guard every
   emission with ``if tr is not None`` — one attribute test per event site,
   no allocation.  A disabled tracer's ``span()`` returns the shared
-  ``_NULL_SPAN`` singleton (no object is created per call).
+  ``_NULL_SPAN`` singleton (no object is created per call).  The
+  module-level ``span()`` is the exception: it always pays its profiler
+  annotation, so it marks per-batch, per-split and per-file work only.
 * **Thread-safe and nestable.**  Events append under one lock; span depth
   is tracked per thread so nested spans reconstruct without relying on
   timestamps.
@@ -44,6 +60,7 @@ __all__ = [
     "active",
     "live",
     "install",
+    "span",
     "tracing",
 ]
 
@@ -67,10 +84,24 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """Live span: records an "X" (complete) event on exit."""
+_annotation: Any = None  # jax.profiler.TraceAnnotation, imported on first use
 
-    __slots__ = ("_tr", "_name", "_args", "_cat", "_t0", "_tid", "_depth")
+
+def _profiled(name: str, args: Optional[dict]) -> Any:
+    """The profiler's annotation for a span (entered by the caller)."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation(name, **args) if args else _annotation(name)
+
+
+class _Span:
+    """Live span: a profiler annotation, plus an "X" (complete) event in the
+    tracer on exit."""
+
+    __slots__ = ("_tr", "_name", "_args", "_cat", "_t0", "_tid", "_depth", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, args: Optional[dict], cat: str):
         self._tr = tr
@@ -79,6 +110,8 @@ class _Span:
         self._cat = cat
 
     def __enter__(self) -> "_Span":
+        self._ann = _profiled(self._name, self._args)
+        self._ann.__enter__()
         self._tid = threading.get_ident()
         self._depth = self._tr._enter_span()
         self._t0 = _now_us()
@@ -89,7 +122,13 @@ class _Span:
         self._tr._exit_span()
         self._tr._emit("X", self._name, self._t0, dur, self._tid,
                        self._args, self._cat, self._depth)
+        self._ann.__exit__(*exc)
         return False
+
+    def set_metadata(self, **args: Any) -> None:
+        """Add args known only inside the span (``TraceAnnotation``'s API)."""
+        self._args = dict(self._args or {}, **args)
+        self._ann.set_metadata(**args)
 
 
 class Tracer:
@@ -214,6 +253,18 @@ class Tracer:
 _DISABLED = Tracer(enabled=False)
 _active: Tracer = _DISABLED
 _active_lock = threading.Lock()
+
+
+def span(name: str, **args: Any) -> Any:
+    """Time ``name`` in both sinks: the profiler's trace, always (recorded
+    only while a profile is captured), and the installed tracer when there
+    is one.  ``args`` are schedule-free (split id, column, bytes, batch
+    ordinal), never a thread id or a time; ``set_metadata(**more)`` on the
+    entered span adds args known only inside it."""
+    tr = _active
+    if tr.enabled:
+        return _Span(tr, name, args or None, "det")
+    return _profiled(name, args)
 
 
 def active() -> Tracer:

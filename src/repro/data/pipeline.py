@@ -30,6 +30,7 @@ from typing import Dict, Iterator, Optional
 
 import numpy as np
 
+from ..core import trace
 from ..core.blockcache import BlockCache
 from ..core.cif import ScanStats
 from ..core.placement import Placement
@@ -86,6 +87,7 @@ class HostPipeline:
             state=state.sampler if state else None,
         )
         self._prefetch_n = prefetch
+        self._made = 0  # batches made: the ordinal on each ``feed.batch`` span
         self._q: Optional[queue.Queue] = None
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
@@ -100,36 +102,41 @@ class HostPipeline:
             self.stats.absorb(r.counters, r.file_bytes)
 
     def _make_batch(self) -> Dict[str, np.ndarray]:
-        it = iter(self.sampler)
-        draws = [next(it) for _ in range(self.batch)]
-        by_split: Dict[int, list] = {}
-        for slot, (sid, rid) in enumerate(draws):
-            by_split.setdefault(sid, []).append((rid, slot))
-        tokens = mask = None
-        for sid, rid_slots in by_split.items():
-            # sorted ids keep the forward-only monotone readers happy; the
-            # whole group decodes in one record_batch call.  Each group
-            # opens its split fresh — the shared block cache (not held-open
-            # readers) carries the decoded dict page + mask blocks across
-            # batches, so a reopen costs file reads, not decodes.
-            rid_slots.sort()
-            uniq = sorted({r for r, _ in rid_slots})
-            sp = self.corpus.open_split(sid, cache=self.cache)
-            t, m = sp.record_batch(uniq, decode=self.decode)
-            self._retire(sp)
-            row_of = {r: i for i, r in enumerate(uniq)}
-            if tokens is None:
+        with trace.span("feed.batch", batch=self._made):
+            self._made += 1
+            it = iter(self.sampler)
+            draws = [next(it) for _ in range(self.batch)]
+            by_split: Dict[int, list] = {}
+            for slot, (sid, rid) in enumerate(draws):
+                by_split.setdefault(sid, []).append((rid, slot))
+            groups = []
+            for sid, rid_slots in by_split.items():
+                # sorted ids keep the forward-only monotone readers happy; the
+                # whole group decodes in one record_batch call.  Each group
+                # opens its split fresh — the shared block cache (not held-open
+                # readers) carries the decoded dict page + mask blocks across
+                # batches, so a reopen costs file reads, not decodes.
+                rid_slots.sort()
+                uniq = sorted({r for r, _ in rid_slots})
+                sp = self.corpus.open_split(sid, cache=self.cache)
+                t, m = sp.record_batch(uniq, decode=self.decode)
+                self._retire(sp)
+                groups.append((rid_slots, uniq, t, m))
+            with trace.span("feed.assemble"):
+                _, _, t, m = groups[0]
                 tokens = np.empty((self.batch,) + t.shape[1:], t.dtype)
                 mask = np.empty((self.batch,) + m.shape[1:], m.dtype)
-            for rid, slot in rid_slots:
-                tokens[slot] = t[row_of[rid]]
-                mask[slot] = m[row_of[rid]]
-        labels = np.concatenate(
-            [tokens[:, 1:], np.zeros((tokens.shape[0], 1), np.int32)], axis=1
-        )
-        lm = mask.astype(np.float32)
-        lm[:, -1] = 0.0
-        return {"tokens": tokens, "labels": labels, "loss_mask": lm}
+                for rid_slots, uniq, t, m in groups:
+                    row_of = {r: i for i, r in enumerate(uniq)}
+                    for rid, slot in rid_slots:
+                        tokens[slot] = t[row_of[rid]]
+                        mask[slot] = m[row_of[rid]]
+                labels = np.concatenate(
+                    [tokens[:, 1:], np.zeros((tokens.shape[0], 1), np.int32)], axis=1
+                )
+                lm = mask.astype(np.float32)
+                lm[:, -1] = 0.0
+            return {"tokens": tokens, "labels": labels, "loss_mask": lm}
 
     # -- prefetching --------------------------------------------------------
     def _worker(self) -> None:
@@ -144,13 +151,16 @@ class HostPipeline:
     def __iter__(self) -> Iterator[Dict[str, np.ndarray]]:
         if self._prefetch_n <= 0:
             while True:
-                yield self._make_batch()
+                with trace.span("feed.wait"):
+                    batch = self._make_batch()
+                yield batch
         self._q = queue.Queue(maxsize=self._prefetch_n)
         self._thread = threading.Thread(target=self._worker, daemon=True)
         self._thread.start()
         self._last_state = self.state()
         while True:
-            item = self._q.get()
+            with trace.span("feed.wait"):
+                item = self._q.get()
             if isinstance(item, Exception):
                 raise item
             batch, st = item

@@ -42,6 +42,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 import numpy as np
 
 from ..core import BYTES, COFWriter, INT32, MAP, STRING, ARRAY, ColumnFormat, Schema
+from ..core import trace
 from ..core.cif import CIFReader, list_splits, read_schema
 from ..core.encodings import (  # packing lives in the encoding layer now
     bits_for,
@@ -73,7 +74,12 @@ def legacy_token_schema() -> Schema:
 def device_decode_batch(words: np.ndarray, bits: int, n: int, dictionary: np.ndarray) -> np.ndarray:
     """decode="device": ship packed words to the accelerator as-is; the
     Pallas kernels bit-unpack (VPU shifts) and dictionary-gather there.
-    On the CPU backend the same kernels run in interpret mode."""
+    On the CPU backend the same kernels run in interpret mode.
+
+    Spans: ``decode.dispatch`` (host to device copies and the kernel
+    launches), ``decode.wait`` (the kernels running), ``decode.d2h`` (the
+    decoded tokens copied back)."""
+    import jax
     import jax.numpy as jnp
 
     from ..kernels import ops
@@ -81,18 +87,24 @@ def device_decode_batch(words: np.ndarray, bits: int, n: int, dictionary: np.nda
 
     interp = ops.interpret_mode()
     b = words.shape[0]
-    if bits == 32:  # giant dictionaries: words already ARE the codes
-        codes = jnp.asarray(words.astype(np.int32).reshape(b, -1)[:, :n])
-    else:
-        codes = ops.bitunpack(jnp.asarray(words.reshape(-1)), bits, interpret=interp)
-        codes = codes.reshape(b, -1)[:, :n]
     # every split has its own dictionary length: pad it to a power of two, and
     # to at least the kernel's CHUNK, so the decode compiles once per size
     # class, not once per split
-    table = np.zeros(max(CHUNK, 1 << (len(dictionary) - 1).bit_length()), np.int32)
-    table[: len(dictionary)] = dictionary
-    toks = ops.dict_decode(codes.reshape(-1), jnp.asarray(table), interpret=interp)
-    return np.asarray(toks.reshape(b, n), np.int32)
+    size = max(CHUNK, 1 << (len(dictionary) - 1).bit_length())
+    with trace.span("decode.dispatch", bytes=words.nbytes + 4 * size):
+        if bits == 32:  # giant dictionaries: words already ARE the codes
+            codes = jnp.asarray(words.astype(np.int32).reshape(b, -1)[:, :n])
+        else:
+            codes = ops.bitunpack(jnp.asarray(words.reshape(-1)), bits, interpret=interp)
+            codes = codes.reshape(b, -1)[:, :n]
+        table = np.zeros(size, np.int32)
+        table[: len(dictionary)] = dictionary
+        toks = ops.dict_decode(codes.reshape(-1), jnp.asarray(table), interpret=interp)
+        toks = toks.reshape(b, n)
+    with trace.span("decode.wait"):
+        jax.block_until_ready(toks)
+    with trace.span("decode.d2h", bytes=4 * b * n):
+        return np.asarray(toks, np.int32)
 
 
 def pack_bits(mask: np.ndarray) -> bytes:
@@ -185,23 +197,24 @@ class TokenSplit:
         self.legacy = schema.type_of("tokens").kind == "bytes"
         from ..core.cif import SplitReader
 
-        # projection pushdown: meta.col is never opened for training
-        self.reader = SplitReader(
-            split_dir, schema, ["tokens", "n_tokens", "loss_mask"],
-            split_id=split_id, placement=placement, fault_plan=fault_plan,
-            policy=policy, fail=fail, cache=cache,
-        )
-        if self.legacy:
-            self.dictionary = np.load(os.path.join(split_dir, "tokens.dict.npy"))
-            with open(os.path.join(split_dir, "tokens.meta.json")) as f:
-                m = json.load(f)
-            self.bits = m["bits"]
-            self.seq_len = m["seq_len"]
-        else:
-            page = self.reader.readers["tokens"].dict_page()
-            self.dictionary = np.asarray(page.values, np.int32)
-            self.bits = page.bits
-            self.seq_len = int(page.cell_lens[0]) if len(page.cell_lens) else 0
+        with trace.span("split.open", split=split_id):
+            # projection pushdown: meta.col is never opened for training
+            self.reader = SplitReader(
+                split_dir, schema, ["tokens", "n_tokens", "loss_mask"],
+                split_id=split_id, placement=placement, fault_plan=fault_plan,
+                policy=policy, fail=fail, cache=cache,
+            )
+            if self.legacy:
+                self.dictionary = np.load(os.path.join(split_dir, "tokens.dict.npy"))
+                with open(os.path.join(split_dir, "tokens.meta.json")) as f:
+                    m = json.load(f)
+                self.bits = m["bits"]
+                self.seq_len = m["seq_len"]
+            else:
+                page = self.reader.readers["tokens"].dict_page()
+                self.dictionary = np.asarray(page.values, np.int32)
+                self.bits = page.bits
+                self.seq_len = int(page.cell_lens[0]) if len(page.cell_lens) else 0
 
     def __len__(self) -> int:
         return self.reader.n_records
@@ -225,19 +238,20 @@ class TokenSplit:
         rd = self.reader.readers
         if self.legacy:
             return self._record_batch_legacy(ids, decode)
-        words, dictionary, bits, n = rd["tokens"].read_packed(ids)
-        ns = np.asarray(rd["n_tokens"].read_many(ids))
-        msk_raw = rd["loss_mask"].read_many(ids)
         b = len(ids)
-        if b == 0:
-            z = np.empty((0, self.seq_len), np.int32)
-            return z, z.copy()
-        assert (ns == n).all(), "sequences in one split share seq_len"
-        # read_many hands back RaggedColumn views: equal-length cells gather
-        # with one fancy index straight off the column-file buffer.
-        mask = np.unpackbits(
-            msk_raw.as_matrix(), axis=1, bitorder="little"
-        )[:, :n].astype(np.int32)
+        with trace.span("split.read_packed", rows=b):
+            words, dictionary, bits, n = rd["tokens"].read_packed(ids)
+            ns = np.asarray(rd["n_tokens"].read_many(ids))
+            msk_raw = rd["loss_mask"].read_many(ids)
+            if b == 0:
+                z = np.empty((0, self.seq_len), np.int32)
+                return z, z.copy()
+            assert (ns == n).all(), "sequences in one split share seq_len"
+            # read_many hands back RaggedColumn views: equal-length cells gather
+            # with one fancy index straight off the column-file buffer.
+            mask = np.unpackbits(
+                msk_raw.as_matrix(), axis=1, bitorder="little"
+            )[:, :n].astype(np.int32)
         if decode == "packed":
             return words, mask
         if decode == "device":

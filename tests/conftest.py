@@ -25,3 +25,28 @@ def make_crawl_records(n, seed=0, content_bytes=256):
     from repro.launch.load_data import synth_crawl_records
 
     return list(synth_crawl_records(n, seed=seed, content_bytes=content_bytes))
+
+
+def profiled_host_events(log_dir, fn):
+    """``(fn(), host events)``: the events of the host planes of a
+    ``jax.profiler`` capture taken around ``fn``, each with its plane and
+    line, name and start and end in nanoseconds."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0  # annotations only, no Python call tracing
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"), recursive=True)[0]
+    events = [dict(line=(plane.name, line.name), name=ev.name, t0=ev.start_ns,
+                   t1=ev.start_ns + ev.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              if not plane.name.startswith("/device:")
+              for line in plane.lines for ev in line.events]
+    return out, events

@@ -27,7 +27,7 @@ from repro.core import (
 )
 from repro.core import trace
 
-from conftest import make_crawl_records
+from conftest import make_crawl_records, profiled_host_events
 
 T0 = 1300000000
 POLICY = FailurePolicy(max_attempts=4, max_reexecutions=2, seed=0)
@@ -136,19 +136,25 @@ def test_histogram_matches_numpy_percentiles(rnd):
 # -- traced jobs: determinism + reconciliation --------------------------------
 
 
+def _job(root, n_workers, plan=None, policy=None):
+    """The fig1 where-job; returns (result, the reader's ScanStats)."""
+    p = Placement(8, 4)
+    r = CIFReader(root, columns=["url", "metadata"],
+                  fault_plan=plan, failure_policy=policy)
+    ids, ob = r.job_inputs(batch_size=512, where=fig1_where(), placement=p)
+    res = run_job(ids, reduce_fn=fig1_reduce, n_hosts=4, placement=p,
+                  open_split_batches=ob, map_batch_fn=fig1_map_batch(),
+                  n_workers=n_workers, fault_plan=plan,
+                  failure_policy=policy, scan_stats=r.stats)
+    return res, r.stats
+
+
 def _traced_job(root, n_workers, plan=None, policy=None):
     """Run the fig1 where-job under a fresh tracer; readers MUST be
     constructed inside the tracing scope (they capture the tracer)."""
     with trace.tracing() as tr:
-        p = Placement(8, 4)
-        r = CIFReader(root, columns=["url", "metadata"],
-                      fault_plan=plan, failure_policy=policy)
-        ids, ob = r.job_inputs(batch_size=512, where=fig1_where(), placement=p)
-        res = run_job(ids, reduce_fn=fig1_reduce, n_hosts=4, placement=p,
-                      open_split_batches=ob, map_batch_fn=fig1_map_batch(),
-                      n_workers=n_workers, fault_plan=plan,
-                      failure_policy=policy, scan_stats=r.stats)
-    return tr, res, r.stats
+        res, stats = _job(root, n_workers, plan, policy)
+    return tr, res, stats
 
 
 def test_counter_view_bit_identical_serial_vs_concurrent(crawl):
@@ -158,6 +164,30 @@ def test_counter_view_bit_identical_serial_vs_concurrent(crawl):
     assert tr1.counter_view() == tr4.counter_view()
     # and the sched-excluded events really were present (claims happened)
     assert any(e[6] == "sched" for e in tr4.events())
+
+
+JOB_SPANS = {"job.plan", "job.map", "job.shuffle", "job.reduce", "split",
+             "storage.read", "colfile.verify"}
+
+
+def test_counter_view_covers_the_storage_spans(crawl):
+    """The serial-vs-concurrent identity holds with the file reads and CRC
+    verifies in the view, each with its column or byte count."""
+    tr1, _res1, _ = _traced_job(crawl, 1)
+    tr4, _res4, _ = _traced_job(crawl, 4)
+    assert JOB_SPANS <= {e[1] for e in tr1.events() if e[0] == "X"}
+    assert tr1.counter_view() == tr4.counter_view()
+    view = json.loads(tr1.counter_view())
+    reads = [json.loads(r["args"]) for r in view if r["name"] == "storage.read"]
+    assert {a["column"] for a in reads} == {"url", "metadata"}
+    assert all(a["bytes"] > 0 for a in reads)
+
+
+def test_job_spans_reach_the_profile_without_a_tracer(crawl, tmp_path):
+    assert trace.live() is None
+    (res, _), events = profiled_host_events(tmp_path, lambda: _job(crawl, 2))
+    assert JOB_SPANS <= {e["name"] for e in events}
+    assert res.output == _traced_job(crawl, 1)[1].output
 
 
 def test_counter_view_bit_identical_under_faults(crawl):
