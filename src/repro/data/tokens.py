@@ -83,14 +83,14 @@ def device_decode_batch(words: np.ndarray, bits: int, n: int, dictionary: np.nda
     import jax.numpy as jnp
 
     from ..kernels import ops
-    from ..kernels.dict_decode import CHUNK
+    from ..kernels.dict_decode import MIN_TABLE
 
     interp = ops.interpret_mode()
     b = words.shape[0]
     # every split has its own dictionary length: pad it to a power of two, and
-    # to at least the kernel's CHUNK, so the decode compiles once per size
+    # to at least the kernel's MIN_TABLE, so the decode compiles once per size
     # class, not once per split
-    size = max(CHUNK, 1 << (len(dictionary) - 1).bit_length())
+    size = max(MIN_TABLE, 1 << (len(dictionary) - 1).bit_length())
     with trace.span("decode.dispatch", bytes=words.nbytes + 4 * size):
         if bits == 32:  # giant dictionaries: words already ARE the codes
             codes = jnp.asarray(words.astype(np.int32).reshape(b, -1)[:, :n])

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from .bitunpack import LANE, bitunpack_tiles
-from .dict_decode import dict_decode_rows, dict_decode_scalar
+from .dict_decode import BLOCK, dict_decode_rows, dict_decode_scalar
 from .filter_compact import compact_indices
 
 
@@ -44,11 +44,9 @@ def bitunpack(words: jax.Array, bits: int, interpret: bool = False) -> jax.Array
 
 @partial(jax.jit, static_argnames=("interpret",))
 def dict_decode(codes: jax.Array, table: jax.Array, interpret: bool = False) -> jax.Array:
-    """codes: (N,) int32, table: (V,) -> (N,) decoded values."""
-    tile = 32 * LANE
-    c, n = _pad_to(codes, tile)
-    out = dict_decode_scalar(c.reshape(-1, LANE), table, interpret=interpret)
-    return out.reshape(-1)[:n]
+    """codes: (N,) int32, table: (V,) of a 32-bit dtype -> (N,) decoded values."""
+    c, n = _pad_to(codes, BLOCK)
+    return dict_decode_scalar(c, table, interpret=interpret)[:n]
 
 
 @partial(jax.jit, static_argnames=("interpret",))

@@ -27,13 +27,33 @@ def test_bitunpack(rng, bits, n):
 
 
 @pytest.mark.parametrize("vdtype", [jnp.int32, jnp.float32])
-@pytest.mark.parametrize("n,v", [(4096, 16), (9000, 700)])
+# 65,536 and 131,072 are the token path's size classes for 50k and 100k
+# vocabularies; 300,000 entries span three row tiles of the kernel's grid
+@pytest.mark.parametrize(
+    "n,v", [(4096, 16), (9000, 700), (2048, 65536), (2048, 131072), (2048, 300_000)]
+)
 def test_dict_decode(rng, n, v, vdtype):
-    codes = jnp.asarray(rng.integers(0, v, size=(n,)), jnp.int32)
-    table = jnp.asarray(rng.normal(size=(v,)) * 100, vdtype)
+    codes = rng.integers(0, v, size=(n,))
+    # the first and last lane of the first, second and last table row
+    edges = [c for c in (0, 127, 128, v - 128, v - 1) if 0 <= c < v]
+    codes[: len(edges)] = edges
+    if vdtype == jnp.int32:  # token ids of a 100k vocabulary
+        table = rng.integers(0, 100_352, size=(v,)).astype(np.int32)
+        table[-1] = 100_351
+    else:  # the kernel moves bits: signs, -0.0 and inf come back as stored
+        table = (rng.normal(size=(v,)) * 100).astype(np.float32)
+        table[:4] = [-0.0, np.inf, -np.inf, -1.5]
+        codes[len(edges): len(edges) + 4] = np.arange(4)
+    codes, table = jnp.asarray(codes, jnp.int32), jnp.asarray(table)
     got = ops.dict_decode(codes, table, interpret=True)
     want = ref.dict_decode_ref(codes, table)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32), np.asarray(want).view(np.uint32))
+
+
+def test_dict_decode_takes_32_bit_tables():
+    with pytest.raises(TypeError, match="32-bit"):
+        ops.dict_decode(jnp.zeros((8,), jnp.int32), jnp.zeros((16,), jnp.int16), interpret=True)
 
 
 @pytest.mark.parametrize("d", [128, 512])

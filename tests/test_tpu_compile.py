@@ -45,8 +45,10 @@ def one_chip():
 
 CASES = {
     "bitunpack": (partial(ops.bitunpack, bits=16), [((TOKENS // 2,), jnp.uint32)]),
-    # the token path pads each split's dictionary to a power of two
+    # the token path pads each split's dictionary to a power of two: TinyLlama's
+    # 32,000 ids take the 32,768 class, StableLM 2's 100,352 the 131,072 class
     "dict_decode": (ops.dict_decode, [((TOKENS,), jnp.int32), ((32768,), jnp.int32)]),
+    "dict_decode_131072": (ops.dict_decode, [((TOKENS,), jnp.int32), ((131072,), jnp.int32)]),
     "dict_embed": (ops.dict_embed, [
         ((TOKENS,), jnp.int32), ((4096,), jnp.int32), ((32000, 2048), jnp.bfloat16),
     ]),
