@@ -5,7 +5,8 @@ as ``training.train_loop.fit`` places it (``jax.device_put`` of
 ``tokens``/``labels``/``loss_mask`` to the batch sharding) and waited for.
 The window's rate is every row of every batch delivered, as placed, times
 the sequence length, over the time from the window's start to the last
-delivery.
+delivery. In a traced run each placement is a ``bench.place_batch`` span
+whose ``bytes`` are what it copies from the host.
 
 For the check it keeps the first ids of every delivered row on the host, the
 shape and sharding of every delivered batch, and a seeded sample of the
@@ -38,6 +39,12 @@ def host_pipeline(corpus, cfg: Dict, traffic: Dict, seed: int):
                         prefetch=traffic["prefetch"], decode=traffic["decode"])
 
 
+def host_bytes(arrays: Dict) -> int:
+    """Bytes that placing ``arrays`` copies from the host: arrays already on
+    a device count 0."""
+    return sum(v.nbytes for v in arrays.values() if isinstance(v, np.ndarray))
+
+
 def _drain(pipe) -> int:
     """Batches the prefetch thread makes after ``stop()``. The thread is not
     joined by ``stop()``: take what it queues until it ends, so that the
@@ -61,15 +68,15 @@ def run(cfg: Dict, traffic: Dict, corpus, seed: int, win: Window, devices,
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     b, s = cfg["batch_per_host"], cfg["seq_len"]
-    pipe = make_pipeline(win.source(corpus), cfg, traffic, seed)
+    pipe = make_pipeline(corpus, cfg, traffic, seed)
     sharding = NamedSharding(Mesh(np.asarray(devices), ("data",)), P("data", None))
     it = iter(pipe)
 
     def deliver():
-        with win.spans("bench.next_batch"):
-            batch = next(it)
-        with win.spans("bench.place_batch"):
-            placed = jax.device_put({k: batch[k] for k in BATCH_KEYS}, sharding)
+        batch = next(it)
+        host = {k: batch[k] for k in BATCH_KEYS}
+        with win.spans("bench.place_batch", bytes=host_bytes(host)):
+            placed = jax.device_put(host, sharding)
             return batch, jax.block_until_ready(placed)
 
     # every split's dictionary and code width, then the whole path
@@ -120,7 +127,7 @@ def run(cfg: Dict, traffic: Dict, corpus, seed: int, win: Window, devices,
         "misplaced": misplaced,
         "counters": counters(pipe.stats),
         "tokens_made": made * b * s,
-        "decode_tokens_per_call": b * s,
+        "batch_tokens": b * s,
         "batches_by_tenth": np.bincount(
             np.minimum((10 * np.asarray(done) / win.seconds).astype(int), 9),
             minlength=10).tolist(),

@@ -1,6 +1,7 @@
-"""``dict_decode``'s share of its memory roofline: the bytes the decode
-needs (``roofline.dict_decode_bytes``) over HBM bandwidth, against the
-traced time of the kernel's operations."""
+"""``dict_decode``'s share of its memory roofline: the bytes the decode of the
+window's batches needs (``roofline.dict_decode_bytes``) over HBM bandwidth, against
+the traced time of the kernel's operations on every chip. A CPU trace has no
+device plane, and reads nothing."""
 from bench.roofline import kernel_roofline
 
 OP = r"custom-call\(.*tpu_custom_call"  # the Pallas kernel, not the padding around it

@@ -9,6 +9,8 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
+from bench import spans, tracing
+
 PEAKS: Dict[str, Dict[str, float]] = {
     "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9},
 }
@@ -33,23 +35,26 @@ def bitunpack_bytes(n_tokens: int, bits: int) -> float:
 
 
 def kernel_roofline(ctx: Dict, kernel: str, op: str) -> Optional[float]:
-    """Percent of the least time (bytes over HBM bandwidth) in the traced
-    time of the operations ``op`` (a regex) of the jitted ``kernel``, over
-    the calls traced in the window; None where the trace holds none."""
-    from bench.tracing import kernel_ops
+    """Percent of the least time in the traced time of the operations ``op``
+    (a regex) of the jitted ``kernel``, summed over every device plane.
 
-    if ctx.get("events") is None:
-        return None
-    ops = kernel_ops(ctx["events"], kernel, op)
+    The least time is the bytes that the window's batches need over one
+    chip's HBM bandwidth: each ``feed.batch`` span that ends inside the
+    window counts ``batch_tokens`` tokens and its split's dictionary once.
+    So the same work reads the same, whether one call on one chip decodes a
+    batch or a call a chip on several. None where the trace holds no such
+    operation or no batch; a CPU trace has no device plane."""
+    got = spans.window_batches(ctx.get("events"))
+    ops = tracing.kernel_ops(ctx["events"], kernel, op) if got is not None else []
     if not ops:
         return None
-    n = ctx["decode_tokens_per_call"]
+    t = ctx["batch_tokens"]
     if kernel == "dict_decode":
-        per_call = dict_decode_bytes(n, ctx["dict_len_mean"])
+        per_batch = dict_decode_bytes(t, ctx["dict_len_mean"])
     elif kernel == "bitunpack":
-        per_call = bitunpack_bytes(n, ctx["bits"])
+        per_batch = bitunpack_bytes(t, ctx["bits"])
     else:
         raise KeyError(kernel)
     seconds = sum(e["dur"] for e in ops) / 1e9
-    least = len(ops) * per_call / peaks(ctx["device_kind"])["hbm_bytes_per_s"]
+    least = len(got[2]) * per_batch / peaks(ctx["device_kind"])["hbm_bytes_per_s"]
     return 100.0 * least / seconds

@@ -16,7 +16,7 @@ import os
 import re
 from dataclasses import dataclass
 from types import ModuleType
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -24,6 +24,14 @@ ROOT = os.path.dirname(BENCH_DIR)
 
 class SpecError(ValueError):
     pass
+
+
+def four_chip_ok(chips: Sequence[int]) -> bool:
+    """The cells' chip counts keep the four-chip rule: each cell takes 1 or 4
+    chips, and at most half of the cells, rounded down, take 4; one such
+    cell is always allowed."""
+    n_four = sum(c == 4 for c in chips)
+    return all(c in (1, 4) for c in chips) and n_four <= max(1, len(chips) // 2)
 
 
 @dataclass

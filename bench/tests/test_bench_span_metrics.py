@@ -10,6 +10,7 @@ from bench import spec, tracing
 
 HOST = dict(plane="/host:CPU", line="python")
 NEW = ("batch_ms.feed", "read_ms.feed", "verify_ms.feed")
+LINK = ("h2d_bytes_per_token.feed", "d2h_bytes_per_token.feed")
 
 
 def ev(name, t0, dur, **kw):
@@ -74,9 +75,69 @@ def test_new_entries_load_and_read_spans():
         assert spec.load_reader(m["name"])({"events": window_events()}) > 0
 
 
+def link_events():
+    """``window_events()`` (3 batches end inside the window) with the spans
+    that carry bytes: three of each name end inside it, one before, one after."""
+    ms = 1e6
+    ev_ = window_events()
+    for end in (0.4, 2.4, 5.9, 10.9, 11.5):
+        ev_.append(ev("decode.dispatch", (end - 0.2) * ms, 0.2 * ms, bytes=1000))
+        ev_.append(ev("decode.d2h", (end - 0.1) * ms, 0.1 * ms, bytes=2000))
+    for end in (0.9, 3, 7, 11, 12):  # 11: ends on the edge, counts
+        ev_.append(ev("bench.place_batch", (end - 0.5) * ms, 0.5 * ms, bytes=3000))
+    ev_.append(ev("decode.d2h", 5 * ms, 0.1 * ms, plane="/device:TPU:0", bytes=9))  # not a host span
+    return ev_
+
+
+def read_link(metric, events, batch_tokens=500):
+    return spec.load_reader(metric)({"events": events, "batch_tokens": batch_tokens})
+
+
+def test_link_bytes_per_token_count_the_spans_ending_in_the_window():
+    events = link_events()
+    assert read_link("h2d_bytes_per_token.feed", events) == pytest.approx(
+        (3 * 1000 + 3 * 3000) / (3 * 500))
+    assert read_link("d2h_bytes_per_token.feed", events) == pytest.approx(3 * 2000 / (3 * 500))
+    # placement of arrays already on the chips copies nothing: it counts 0
+    on_chip = [dict(e, bytes=0) if e["name"] == "bench.place_batch" else e for e in events]
+    assert read_link("h2d_bytes_per_token.feed", on_chip) == pytest.approx(2.0)
+
+
+@pytest.mark.parametrize("metric", LINK)
+def test_link_without_its_spans_reads_nothing(metric):
+    assert read_link(metric, None) is None and read_link(metric, []) is None
+    events = link_events()
+    assert read_link(metric, [e for e in events if e["name"] != "feed.batch"]) is None
+    kept = {"h2d_bytes_per_token.feed": ("decode.dispatch", "bench.place_batch"),
+            "d2h_bytes_per_token.feed": ("decode.d2h",)}[metric]
+    assert read_link(metric, [e for e in events if e["name"] not in kept]) is None
+    no_bytes = [{k: v for k, v in e.items() if k != "bytes"} for e in events]
+    assert read_link(metric, no_bytes) is None
+
+
+@pytest.mark.parametrize("metric", LINK)
+def test_link_entries_load_and_read_spans(metric):
+    bench = spec.load_benchmark()
+    m = next(m for m in bench["per_layer"] if m["name"] == metric)
+    assert m["source"] == "program_span" and m["moves"] == "feed_tokens_per_s"
+    assert m["layer"] == "host-device link" and m["unit"] == "B/token"
+    assert m["better"] == "lower" and m["workloads"] == [w["name"] for w in bench["workloads"]]
+    assert read_link(metric, link_events()) > 0
+
+
+def test_d2h_reads_nothing_for_a_feed_that_keeps_its_batch_on_the_chips():
+    events = [e for e in link_events() if e["name"] != "decode.d2h"]
+    assert read_link("d2h_bytes_per_token.feed", events) is None
+    assert read_link("h2d_bytes_per_token.feed", events) == pytest.approx(8.0)
+
+
 def test_tiny_traced_run_reads_the_spans(tmp_path):
     out = bench_tiny.run(bench_tiny.make_root(str(tmp_path)), trace=True)
     assert out["correct"]
-    got = {m: out["metrics"][m]["value"] for m in NEW}
+    got = {m: out["metrics"][m]["value"] for m in NEW + LINK}
     assert all(v > 0 for v in got.values())
     assert got["read_ms.feed"] + got["verify_ms.feed"] < got["batch_ms.feed"]
+    # int32 ids back, and the batch's three 4-byte arrays placed, a token;
+    # at the window's edges a span may count without its batch, or not
+    assert got["d2h_bytes_per_token.feed"] == pytest.approx(4.0, rel=0.1)
+    assert got["h2d_bytes_per_token.feed"] > 12.0

@@ -119,8 +119,9 @@ def test_contract_keys_and_names():
         assert body["name"] == c["name"] and body["source"] == c["source"]
     for w in BENCH["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] == 1 and len(w["why"]) <= 200
+        assert len(w["why"]) <= 200
         assert os.path.exists(os.path.join(spec.BENCH_DIR, "traffic", w["traffic"] + ".json"))
+    assert spec.four_chip_ok([w["chips"] for w in BENCH["workloads"]])
     for m in BENCH["end_to_end"] + BENCH["per_layer"]:
         assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
         assert all(w in CELLS for w in m.get("workloads", []))
@@ -130,6 +131,17 @@ def test_contract_keys_and_names():
     for m in BENCH["per_layer"]:
         assert m["moves"] in e2e and "bound" not in m
         spec.load_reader(m["name"])
+
+
+@pytest.mark.parametrize("chips, ok", [
+    ([4], True), ([1, 1, 4], True), ([1, 4, 1, 4], True), ([1, 4, 4, 4], False),
+    ([4, 1, 4, 1, 4], False), ([1, 1], True), ([4, 4], False), ([2], False),
+    ([1, 8], False), ([1, 1, 1, 0], False),
+])
+def test_four_chip_rule(chips, ok):
+    """1 or 4 chips a cell; at most half of the cells, rounded down, on 4, and
+    one always."""
+    assert spec.four_chip_ok(chips) is ok
 
 
 @pytest.mark.parametrize("cell", CELLS)

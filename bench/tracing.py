@@ -15,7 +15,8 @@ harness opens around the traced part of its window.
   the devices used; idle is the rest of the window.
 - A kernel's time is the sum of the durations of its operations, found by
   the jitted function's module name (``jit_<name>``) and the operation's
-  name or category, as its reader states them.
+  name or category, as its reader states them, on every device plane.
+- A host span keeps its ``bytes`` arg, where it has one.
 - Each idle gap is put down to the shortest host event that covers half of
   it or more (the innermost thing the host was doing), else to the one that
   overlaps it most, and gaps are summed by that event's name.
@@ -33,7 +34,7 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HLO_NAME = re.compile(r"^%?(?P<name>\S+) = .*?\s(?P<op>[a-z][\w-]*)\(")
-_STATS = ("hlo_module", "hlo_category")
+_STATS = ("hlo_module", "hlo_category", "bytes")  # ``bytes``: an arg of a host span
 
 
 def start(log_dir: str) -> None:

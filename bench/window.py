@@ -1,5 +1,5 @@
 """What every traffic loop shares: the measured window, host spans around
-the calls into the program's layers, and the program's counters.
+what the loop itself does, and the program's counters.
 
 A loop (``bench/loops/<loop>.py``) warms up every shape its window uses,
 opens the window, drives the timed path until ``seconds`` have passed, and
@@ -23,37 +23,11 @@ class Spans:
     def __init__(self, on: bool):
         self.on = on
 
-    def __call__(self, name: str):
+    def __call__(self, name: str, **args):
         import jax
 
-        return jax.profiler.TraceAnnotation(name) if self.on else contextlib.nullcontext()
-
-
-class _TracedSplit:
-    def __init__(self, split, spans):
-        self._split, self._spans = split, spans
-
-    def record_batch(self, ids, decode="np"):
-        with self._spans("tokens.record_batch"):
-            return self._split.record_batch(ids, decode=decode)
-
-    def __getattr__(self, name):
-        return getattr(self._split, name)
-
-
-class TracedCorpus:
-    """The corpus as the program sees it, with a span around each split
-    open and each batch read."""
-
-    def __init__(self, corpus, spans):
-        self._corpus, self._spans = corpus, spans
-
-    def open_split(self, split_id, **kw):
-        with self._spans("tokens.open_split"):
-            return _TracedSplit(self._corpus.open_split(split_id, **kw), self._spans)
-
-    def __getattr__(self, name):
-        return getattr(self._corpus, name)
+        return (jax.profiler.TraceAnnotation(name, **args) if self.on
+                else contextlib.nullcontext())
 
 
 def counters(stats) -> Dict[str, int]:
@@ -81,10 +55,6 @@ class Window:
         self.spans = Spans(trace_dir is not None)
         self._span = None
         self.on_open: Optional[Callable[[], None]] = None
-
-    def source(self, corpus):
-        """The corpus with spans around its calls when the run is traced."""
-        return TracedCorpus(corpus, self.spans) if self.spans.on else corpus
 
     def open(self) -> float:
         if self.on_open is not None:
