@@ -15,7 +15,16 @@ encoding layer) — no pipeline-private dictionary sidecars.  ``decode``
 selects the token decode world: "np" (host vectorized), "py" (per-element
 loop, Fig. 8's slow world), "packed" (raw words, caller decodes), or
 "device" (packed words are shipped as-is and the Pallas
-``bitunpack``/``dict_decode`` kernels expand them on-accelerator).
+``bitunpack``/``dict_decode`` kernels expand them on-accelerator); any
+other name is a ``ValueError``.
+
+With decode="device" and a ``sharding`` (the batch's ``NamedSharding``, rows
+over the mesh axis ``data`` as ``fit`` builds it), the batch is made on the
+chips: each chip is sent only its own rows' packed words and mask bits and
+decodes them (``tokens.chips_decode_batch``), labels and the loss mask are
+built row by row where the rows are, and the three arrays are yielded as
+global arrays under that sharding. Nothing comes back to the host. Without a
+sharding every batch is host numpy.
 
 Batch layout: {"tokens": (B,S) int32, "labels": (B,S) int32,
                "loss_mask": (B,S) float32} — labels are next-token shifted,
@@ -35,7 +44,19 @@ from ..core.blockcache import BlockCache
 from ..core.cif import ScanStats
 from ..core.placement import Placement
 from .sampler import SamplerState, ShardedSampler
-from .tokens import TokenCorpus, TokenSplit
+from .tokens import TokenCorpus, TokenSplit, check_decode, chips_decode_batch
+
+
+def labels_and_mask(tokens, mask_bits):
+    """labels and loss_mask of a batch from its tokens and mask bits, row by
+    row: no row needs another's data, so each chip builds its own."""
+    import jax.numpy as jnp
+
+    b, n = tokens.shape
+    labels = jnp.concatenate([tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
+    bits = (mask_bits[:, :, None] >> jnp.arange(8, dtype=jnp.uint8)) & 1
+    lm = bits.reshape(b, -1)[:, :n].astype(jnp.float32)
+    return labels, lm.at[:, -1].set(0.0)
 
 
 @dataclass
@@ -62,10 +83,15 @@ class HostPipeline:
         state: Optional[PipelineState] = None,
         decode: str = "np",
         cache: Optional[BlockCache] = None,
+        sharding=None,
     ):
         self.corpus = corpus
         self.batch = batch_per_host
-        self.decode = decode
+        self.decode = check_decode(decode)
+        if sharding is not None and decode != "device":
+            raise ValueError(f"batches on the chips need decode='device', not {decode!r}")
+        self.sharding = sharding
+        self._layout = None  # ``labels_and_mask`` jitted for ``sharding``, on the first batch
         # decoded-block reuse now lives in the SHARED block cache (the same
         # policy + counters the serving path uses) instead of the ad-hoc
         # oldest-first open-split map earlier revisions kept: splits open
@@ -119,9 +145,13 @@ class HostPipeline:
                 rid_slots.sort()
                 uniq = sorted({r for r, _ in rid_slots})
                 sp = self.corpus.open_split(sid, cache=self.cache)
-                t, m = sp.record_batch(uniq, decode=self.decode)
+                if self.sharding is not None:
+                    groups.append((rid_slots, uniq, sp.read_packed(uniq)))
+                else:
+                    groups.append((rid_slots, uniq, *sp.record_batch(uniq, decode=self.decode)))
                 self._retire(sp)
-                groups.append((rid_slots, uniq, t, m))
+            if self.sharding is not None:
+                return self._on_chips(groups)
             with trace.span("feed.assemble"):
                 _, _, t, m = groups[0]
                 tokens = np.empty((self.batch,) + t.shape[1:], t.dtype)
@@ -137,6 +167,22 @@ class HostPipeline:
                 lm = mask.astype(np.float32)
                 lm[:, -1] = 0.0
             return {"tokens": tokens, "labels": labels, "loss_mask": lm}
+
+    def _on_chips(self, groups) -> Dict:
+        """The batch decoded on the chips of ``sharding``, each its own rows."""
+        import jax
+
+        rows = np.empty((self.batch, 2), np.int64)  # (group, row in it) a slot
+        for k, (rid_slots, uniq, _) in enumerate(groups):
+            row_of = {r: i for i, r in enumerate(uniq)}
+            for rid, slot in rid_slots:
+                rows[slot] = k, row_of[rid]
+        tokens, mask_bits = chips_decode_batch([p for *_, p in groups], rows, self.sharding)
+        if self._layout is None:
+            self._layout = jax.jit(labels_and_mask, out_shardings=(self.sharding, self.sharding))
+        with trace.span("feed.assemble"):
+            labels, lm = jax.block_until_ready(self._layout(tokens, mask_bits))
+        return {"tokens": tokens, "labels": labels, "loss_mask": lm}
 
     # -- prefetching --------------------------------------------------------
     def _worker(self) -> None:

@@ -16,18 +16,24 @@ The dictionary and code width now live IN the column file and are read
 through ``ColumnFileReader.dict_page()``; the packed code words ship to the
 accelerator as-is through ``read_packed`` (the device-decode fast path).
 
-Decode paths (Fig. 8's three worlds):
+Decode paths (Fig. 8's three worlds), ``DECODES``; any other name is a
+``ValueError``:
   * decode="py"     — per-element Python loop      ("Java object churn")
   * decode="np"     — vectorized numpy shifts      ("C++ cast the buffer")
   * decode="packed" — raw packed words, caller decodes
   * decode="device" — kernels.bitunpack + dict_decode: on-device VPU unpack
     (beyond-paper: the compressed codes travel host->HBM, saving PCIe
-    bandwidth; the gather runs as a Pallas kernel)
+    bandwidth; the gather runs as a Pallas kernel).  On one device
+    (``device_decode_batch``) the ids come back to the host; over the chips
+    of a batch sharding (``chips_decode_batch``) each chip decodes only its
+    own rows and the batch stays there.
 
 Batch fast path: ``TokenSplit.record_batch(ids)`` pulls the packed words of
 the whole batch with ONE ``read_packed`` gather, then does ONE vectorized
 unpack and ONE dictionary gather (or one kernel launch for
 decode="device") — no per-record Python loop in front of the training step.
+``TokenSplit.read_packed(ids)`` stops before the decode, for the per-chip
+path.
 
 Pre-encoding-layer corpora (tokens as BYTES + ``tokens.dict.npy`` sidecar)
 still read: the root ``schema.json`` identifies them and ``TokenSplit``
@@ -37,7 +43,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterator, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -71,40 +77,132 @@ def legacy_token_schema() -> Schema:
     ])
 
 
+DECODES = ("py", "np", "packed", "device")
+
+
+def check_decode(decode: str) -> str:
+    """``decode`` if it names a decode world, else ``ValueError``: an unknown
+    name must not fall through to the host path."""
+    if decode not in DECODES:
+        raise ValueError(f"unknown decode {decode!r}; known: {', '.join(DECODES)}")
+    return decode
+
+
+class PackedRows(NamedTuple):
+    """Rows of one split as stored: packed code words ``(B, W)`` uint32, the
+    split's dictionary and code width, the sequence length ``n``, and the
+    loss mask's bits ``(B, ceil(n / 8))`` uint8, least significant first."""
+
+    words: np.ndarray
+    dictionary: np.ndarray
+    bits: int
+    n: int
+    mask_bits: np.ndarray
+
+
+def _device_table(dictionary: np.ndarray) -> np.ndarray:
+    """The dictionary padded to a power of two, and to at least the kernel's
+    ``MIN_TABLE``: every split has its own dictionary length, so the decode
+    compiles once per size class, not once per split."""
+    from ..kernels.dict_decode import MIN_TABLE
+
+    size = max(MIN_TABLE, 1 << (len(dictionary) - 1).bit_length())
+    table = np.zeros(size, np.int32)
+    table[: len(dictionary)] = dictionary
+    return table
+
+
+def _decode_on(device, words: np.ndarray, bits: int, table: np.ndarray):
+    """Copy a block of rows' packed words and the padded table to ``device``
+    (the default device where None) and launch the kernels there:
+    ``bitunpack`` (VPU shifts) and ``dict_decode``. Returns the ids, ``(rows,
+    32 // bits * W)`` int32, not waited for."""
+    import jax
+
+    from ..kernels import ops
+
+    interp = ops.interpret_mode()
+    if bits == 32:  # giant dictionaries: the words already are the codes
+        words = words.view(np.int32)
+    codes, table = jax.device_put((words, table), device)
+    if bits != 32:
+        codes = ops.bitunpack(codes, bits, interpret=interp)
+    return ops.dict_decode(codes, table, interpret=interp)
+
+
 def device_decode_batch(words: np.ndarray, bits: int, n: int, dictionary: np.ndarray) -> np.ndarray:
-    """decode="device": ship packed words to the accelerator as-is; the
-    Pallas kernels bit-unpack (VPU shifts) and dictionary-gather there.
-    On the CPU backend the same kernels run in interpret mode.
+    """decode="device" on one device: ship packed words to the accelerator
+    as-is; the Pallas kernels bit-unpack and dictionary-gather there, and the
+    ids come back. On the CPU backend the same kernels run in interpret mode.
 
     Spans: ``decode.dispatch`` (host to device copies and the kernel
     launches), ``decode.wait`` (the kernels running), ``decode.d2h`` (the
     decoded tokens copied back)."""
     import jax
-    import jax.numpy as jnp
 
-    from ..kernels import ops
-    from ..kernels.dict_decode import MIN_TABLE
-
-    interp = ops.interpret_mode()
-    b = words.shape[0]
-    # every split has its own dictionary length: pad it to a power of two, and
-    # to at least the kernel's MIN_TABLE, so the decode compiles once per size
-    # class, not once per split
-    size = max(MIN_TABLE, 1 << (len(dictionary) - 1).bit_length())
-    with trace.span("decode.dispatch", bytes=words.nbytes + 4 * size):
-        if bits == 32:  # giant dictionaries: words already ARE the codes
-            codes = jnp.asarray(words.astype(np.int32).reshape(b, -1)[:, :n])
-        else:
-            codes = ops.bitunpack(jnp.asarray(words.reshape(-1)), bits, interpret=interp)
-            codes = codes.reshape(b, -1)[:, :n]
-        table = np.zeros(size, np.int32)
-        table[: len(dictionary)] = dictionary
-        toks = ops.dict_decode(codes.reshape(-1), jnp.asarray(table), interpret=interp)
-        toks = toks.reshape(b, n)
+    table = _device_table(dictionary)
+    with trace.span("decode.dispatch", bytes=words.nbytes + table.nbytes):
+        toks = _decode_on(None, words, bits, table)
     with trace.span("decode.wait"):
         jax.block_until_ready(toks)
-    with trace.span("decode.d2h", bytes=4 * b * n):
-        return np.asarray(toks, np.int32)
+    with trace.span("decode.d2h", bytes=toks.nbytes):
+        return np.asarray(toks)[:, :n]
+
+
+def chips_decode_batch(parts: Sequence[PackedRows], rows: np.ndarray, sharding):
+    """decode="device" with the batch kept on the chips. Batch row ``i`` is
+    row ``rows[i, 1]`` of ``parts[rows[i, 0]]``; ``sharding`` divides the
+    batch's rows among devices and keeps each sequence whole. Each device is
+    sent only its own rows' packed words and mask bits, and the table of
+    each split they come from, and decodes them with the one-device path's
+    kernels. Returns ``(tokens, mask_bits)``, global arrays under
+    ``sharding``: ``(B, n)`` int32 and ``(B, ceil(n / 8))`` uint8.
+
+    Spans: ``decode.chips`` (args ``chips``, and ``bytes`` sent to all of
+    them), holding ``decode.dispatch`` (every copy and launch, the same
+    ``bytes``), ``decode.wait``, and ``decode.d2h``: where the one-device
+    path copies the ids back, this one assembles the global arrays where
+    they are, so its ``bytes`` are 0."""
+    import jax
+    import jax.numpy as jnp
+
+    n = parts[0].n
+    if any(p.n != n for p in parts):
+        raise ValueError("the rows of one batch share their length")
+    b = len(rows)
+    mask = np.empty((b,) + parts[0].mask_bits.shape[1:], np.uint8)
+    for k, p in enumerate(parts):
+        mine = rows[:, 0] == k
+        mask[mine] = p.mask_bits[rows[mine, 1]]
+    tables = [_device_table(p.dictionary) for p in parts]
+    # every device's share, gathered on the host: per split its rows' words
+    plans = []
+    for dev, (rs, cs) in sharding.addressable_devices_indices_map((b, n)).items():
+        if cs != slice(None):
+            raise ValueError(f"a sequence must stay whole on its device: {sharding}")
+        mine = rows[rs]
+        ks = list(dict.fromkeys(mine[:, 0].tolist()))
+        at = [np.flatnonzero(mine[:, 0] == k) for k in ks]
+        pieces = [(parts[k].words[mine[i, 1]], parts[k].bits, tables[k]) for k, i in zip(ks, at)]
+        plans.append((dev, pieces, np.concatenate(at), mask[rs]))
+    sent = sum(w.nbytes + t.nbytes for _, pieces, _, _ in plans for w, _, t in pieces)
+    sent += sum(m.nbytes for *_, m in plans)
+    with trace.span("decode.chips", chips=len(plans), bytes=sent):
+        with trace.span("decode.dispatch", bytes=sent):
+            toks, masks = [], []
+            for dev, pieces, order, m in plans:
+                ts = [_decode_on(dev, *p) for p in pieces]
+                if len(ts) > 1:  # rows of several splits: back into batch order
+                    t = jnp.concatenate([t[:, :n] for t in ts])[np.argsort(order)]
+                else:
+                    t = ts[0] if ts[0].shape[1] == n else ts[0][:, :n]
+                toks.append(t)
+                masks.append(jax.device_put(m, dev))
+        with trace.span("decode.wait"):
+            jax.block_until_ready(toks)
+        with trace.span("decode.d2h", bytes=0):
+            return (jax.make_array_from_single_device_arrays((b, n), sharding, toks),
+                    jax.make_array_from_single_device_arrays(mask.shape, sharding, masks))
 
 
 def pack_bits(mask: np.ndarray) -> bytes:
@@ -223,75 +321,60 @@ class TokenSplit:
         t, m = self.record_batch([i], decode=decode)
         return t[0], m[0]
 
-    def record_batch(self, ids, decode: str = "np") -> Tuple[np.ndarray, np.ndarray]:
-        """Batch fetch of sorted, strictly-increasing record ids.
-
-        Packed words come from one ``read_packed`` gather off the dict page
-        (mask/n_tokens via bulk ``read_many``), then the whole batch gets
-        ONE vectorized unpack and ONE dictionary gather (or one kernel
-        launch for decode="device").  Returns ``(tokens, loss_mask)`` shaped
-        ``(B, seq_len)`` int32 — or ``(B, W)`` uint32 packed words for
-        decode="packed".
-        """
+    def read_packed(self, ids) -> PackedRows:
+        """Rows ``ids`` (sorted, strictly increasing) as stored: packed words
+        from one ``read_packed`` gather off the dict page (the legacy layout:
+        its BYTES cells), lengths and mask bits via bulk ``read_many``."""
         ids = list(ids)
         assert all(b > a for a, b in zip(ids, ids[1:])), "ids must be strictly increasing"
         rd = self.reader.readers
-        if self.legacy:
-            return self._record_batch_legacy(ids, decode)
-        b = len(ids)
-        with trace.span("split.read_packed", rows=b):
-            words, dictionary, bits, n = rd["tokens"].read_packed(ids)
+        with trace.span("split.read_packed", rows=len(ids)):
+            if self.legacy:
+                words = rd["tokens"].read_many(ids)
+                dictionary, bits = self.dictionary, self.bits
+            else:
+                words, dictionary, bits, n = rd["tokens"].read_packed(ids)
             ns = np.asarray(rd["n_tokens"].read_many(ids))
             msk_raw = rd["loss_mask"].read_many(ids)
-            if b == 0:
-                z = np.empty((0, self.seq_len), np.int32)
-                return z, z.copy()
+            if not ids:
+                return PackedRows(np.empty((0, 0), np.uint32), np.asarray(dictionary, np.int32),
+                                  bits, self.seq_len, np.empty((0, 0), np.uint8))
+            if self.legacy:
+                n = int(ns[0])
+                words = words.as_matrix().view("<u4").copy()
             assert (ns == n).all(), "sequences in one split share seq_len"
             # read_many hands back RaggedColumn views: equal-length cells gather
             # with one fancy index straight off the column-file buffer.
-            mask = np.unpackbits(
-                msk_raw.as_matrix(), axis=1, bitorder="little"
-            )[:, :n].astype(np.int32)
-        if decode == "packed":
-            return words, mask
-        if decode == "device":
-            return device_decode_batch(words, bits, n, np.asarray(dictionary, np.int32)), mask
-        codes = unpack_codes_batch(words, bits, n)
-        if decode == "py":  # the "Java" path, for Fig. 8 benchmarks
-            toks = np.asarray(
-                [[int(dictionary[c]) for c in row] for row in codes], np.int32
-            )
-        else:
-            toks = np.asarray(dictionary, np.int32)[codes]
-        return toks.astype(np.int32), mask
+            return PackedRows(words, np.asarray(dictionary, np.int32), bits, n,
+                              msk_raw.as_matrix())
 
-    def _record_batch_legacy(self, ids, decode: str) -> Tuple[np.ndarray, np.ndarray]:
-        rd = self.reader.readers
-        raws = rd["tokens"].read_many(ids)
-        ns = np.asarray(rd["n_tokens"].read_many(ids))
-        msk_raw = rd["loss_mask"].read_many(ids)
-        b = len(ids)
-        if b == 0:
+    def record_batch(self, ids, decode: str = "np") -> Tuple[np.ndarray, np.ndarray]:
+        """Batch fetch of sorted, strictly-increasing record ids.
+
+        The batch's packed words and mask come from ``read_packed``, then the
+        whole batch gets ONE vectorized unpack and ONE dictionary gather (or
+        one kernel launch for decode="device").  Returns ``(tokens,
+        loss_mask)`` shaped ``(B, seq_len)`` int32 — or ``(B, W)`` uint32
+        packed words for decode="packed".
+        """
+        check_decode(decode)
+        p = self.read_packed(ids)
+        if not len(p.words):
             z = np.empty((0, self.seq_len), np.int32)
             return z, z.copy()
-        n = int(ns[0])
-        assert (ns == n).all(), "sequences in one split share seq_len"
-        mask = np.unpackbits(
-            msk_raw.as_matrix(), axis=1, bitorder="little"
-        )[:, :n].astype(np.int32)
-        words = raws.as_matrix().view("<u4")
+        mask = np.unpackbits(p.mask_bits, axis=1, bitorder="little")[:, :p.n].astype(np.int32)
         if decode == "packed":
-            return words.copy(), mask
+            return p.words, mask
         if decode == "device":
-            return device_decode_batch(words, self.bits, n, self.dictionary), mask
-        codes = unpack_codes_batch(words, self.bits, n)
-        if decode == "py":
+            return device_decode_batch(p.words, p.bits, p.n, p.dictionary), mask
+        codes = unpack_codes_batch(p.words, p.bits, p.n)
+        if decode == "py":  # the "Java" path, for Fig. 8 benchmarks
             toks = np.asarray(
-                [[int(self.dictionary[c]) for c in row] for row in codes], np.int32
+                [[int(p.dictionary[c]) for c in row] for row in codes], np.int32
             )
         else:
-            toks = self.dictionary[codes].astype(np.int32)
-        return toks, mask
+            toks = p.dictionary[codes]
+        return toks.astype(np.int32), mask
 
     @property
     def position(self) -> int:

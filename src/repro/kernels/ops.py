@@ -34,19 +34,20 @@ def _pad_to(x: jax.Array, mult: int, fill=0) -> Tuple[jax.Array, int]:
 
 @partial(jax.jit, static_argnames=("bits", "interpret"))
 def bitunpack(words: jax.Array, bits: int, interpret: bool = False) -> jax.Array:
-    """words: (W,) uint32 -> (W*32//bits,) int32 codes."""
+    """words: (..., W) uint32 -> (..., W*32//bits) int32 codes."""
     tile = 64 * LANE
-    w, n = _pad_to(words, tile)
+    w, n = _pad_to(words.reshape(-1), tile)
     tiles = w.reshape(-1, LANE)
     out = bitunpack_tiles(tiles, bits, interpret=interpret)
-    return out.reshape(-1)[: n * (32 // bits)]
+    return out.reshape(-1)[: n * (32 // bits)].reshape(words.shape[:-1] + (-1,))
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def dict_decode(codes: jax.Array, table: jax.Array, interpret: bool = False) -> jax.Array:
-    """codes: (N,) int32, table: (V,) of a 32-bit dtype -> (N,) decoded values."""
-    c, n = _pad_to(codes, BLOCK)
-    return dict_decode_scalar(c, table, interpret=interpret)[:n]
+    """codes: (...) int32, table: (V,) of a 32-bit dtype -> decoded values
+    shaped as ``codes``."""
+    c, n = _pad_to(codes.reshape(-1), BLOCK)
+    return dict_decode_scalar(c, table, interpret=interpret)[:n].reshape(codes.shape)
 
 
 @partial(jax.jit, static_argnames=("interpret",))

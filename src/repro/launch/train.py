@@ -27,7 +27,8 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--decode", default="np", choices=["py", "np", "device"],
-                    help="token decode path of the input pipeline")
+                    help="token decode path of the input pipeline; 'device' "
+                         "decodes each chip's rows of a batch on that chip")
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--production-mesh", action="store_true",
                     help="use the 16x16 pod mesh (requires 256 devices)")

@@ -46,6 +46,9 @@ def fit(
     step_fn, (sp, bp) = build_train_step(cfg, sh, mesh, shape, opts)
     state_sh = named(sp, mesh)
     batch_sh = named(bp, mesh)
+    if pipeline.decode == "device":
+        # each chip decodes its own rows: batches arrive on the chips, placed
+        pipeline.sharding = batch_sh["tokens"]
 
     ckpt = Checkpointer(loop.ckpt_dir, loop.max_keep) if loop.ckpt_dir else None
     start = 0
@@ -71,9 +74,10 @@ def fit(
     compiled = None
     compile_s = 0.0
     for step in range(start, loop.steps):
-        batch_np = next(it)
+        # host arrays are copied to the chips; arrays already placed under
+        # ``batch_sh`` are passed through as they are
         batch = jax.device_put(
-            {k: v for k, v in batch_np.items() if k in ("tokens", "labels", "loss_mask")},
+            {k: v for k, v in next(it).items() if k in ("tokens", "labels", "loss_mask")},
             batch_sh,
         )
         if compiled is None:

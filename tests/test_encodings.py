@@ -334,6 +334,7 @@ def test_legacy_token_corpus_still_reads(tmp_path, rnd):
     modes included."""
     from repro.data.tokens import (
         TokenCorpus, bits_for, legacy_token_schema, pack_bits, pack_codes,
+        unpack_codes_batch,
     )
 
     root = str(tmp_path / "legacy")
@@ -375,6 +376,14 @@ def test_legacy_token_corpus_still_reads(tmp_path, rnd):
     np.testing.assert_array_equal(t_py, t_np)
     t1, _ = corpus.open_split(0).record(2, decode="np")
     np.testing.assert_array_equal(t1, seqs[2])
+    t_dev, m_dev = corpus.open_split(0).record_batch(ids, decode="device")
+    np.testing.assert_array_equal(t_dev, t_np)
+    np.testing.assert_array_equal(m_dev, m)
+    words, _ = corpus.open_split(0).record_batch(ids, decode="packed")
+    np.testing.assert_array_equal(
+        dictionary[unpack_codes_batch(words, bits, seq_len)], t_np)
+    with pytest.raises(ValueError, match="unknown decode"):
+        corpus.open_split(0).record_batch(ids, decode="gpu")
 
 
 def test_forced_delta_falls_back_per_block_when_inapplicable():
