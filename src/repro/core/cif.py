@@ -236,6 +236,12 @@ def format_storage_report(root: str) -> str:
     return "\n".join(lines)
 
 
+# the ReadCounters that ScanStats sums over every reader a scan opens
+_READ_COUNTERS = ("bytes_touched", "bytes_decoded", "cells_decoded", "cells_skipped",
+                  "blocks_decompressed", "cache_hits", "cache_misses", "cache_evictions",
+                  "bytes_served_from_cache")
+
+
 @dataclass
 class ScanStats:
     """Aggregated instrumentation across a scan — the paper's Table 1 columns."""
@@ -301,16 +307,15 @@ class ScanStats:
 
     def absorb(self, c: ReadCounters, file_bytes: int) -> None:
         self.bytes_io += file_bytes
-        self.bytes_touched += c.bytes_touched
-        self.bytes_decoded += c.bytes_decoded
-        self.cells_decoded += c.cells_decoded
-        self.cells_skipped += c.cells_skipped
-        self.blocks_decompressed += c.blocks_decompressed
-        self.cache_hits += c.cache_hits
-        self.cache_misses += c.cache_misses
-        self.cache_evictions += c.cache_evictions
-        self.bytes_served_from_cache += c.bytes_served_from_cache
+        self.absorb_since(c, ReadCounters())
         self.files_opened += 1
+
+    def absorb_since(self, c: ReadCounters, since: ReadCounters) -> None:
+        """Fold what a reader that stays open counted since ``since`` (its
+        counters at the last fold): ``absorb`` without the file's bytes_io
+        and files_opened, which whoever opened it charges once."""
+        for k in _READ_COUNTERS:
+            setattr(self, k, getattr(self, k) + getattr(c, k) - getattr(since, k))
 
     def absorb_failures(self, f: FailureStats) -> None:
         self.checksum_failures += f.checksum_failures

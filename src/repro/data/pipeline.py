@@ -5,18 +5,23 @@ mask columns are opened), lazy decode, split->host co-location (CPP analog),
 and a prefetch thread so storage decode overlaps the train step.
 
 Batches are built on the columnar fast path: sampled ``(split, record)`` ids
-are grouped by split and sorted within each split (respecting the
-forward-only monotone readers — no reopen-on-AssertionError churn), each
-group is fetched with ONE ``TokenSplit.record_batch`` call (one packed-word
-gather off the split's dict-encoded token page + one unpack + one
-dictionary gather), and rows land in preallocated ``(B, S)`` arrays.  The
-dictionary itself lives in the column file's dict page (the generic
-encoding layer) — no pipeline-private dictionary sidecars.  ``decode``
-selects the token decode world: "np" (host vectorized), "py" (per-element
-loop, Fig. 8's slow world), "packed" (raw words, caller decodes), or
-"device" (packed words are shipped as-is and the Pallas
-``bitunpack``/``dict_decode`` kernels expand them on-accelerator); any
-other name is a ``ValueError``.
+are grouped by split and sorted within each split, each group is fetched
+with ONE ``TokenSplit.record_batch`` call (one packed-word gather off the
+split's dict-encoded token page + one unpack + one dictionary gather), and
+rows land in preallocated ``(B, S)`` arrays.  The dictionary itself lives
+in the column file's dict page (the generic encoding layer) — no
+pipeline-private dictionary sidecars.  ``decode`` selects the token decode
+world: "np" (host vectorized), "py" (per-element loop, Fig. 8's slow world),
+"packed" (raw words, caller decodes), or "device" (packed words are shipped
+as-is and the Pallas ``bitunpack``/``dict_decode`` kernels expand them
+on-accelerator); any other name is a ``ValueError``.
+
+The sampler walks one split's records in order, so one split serves many
+batches in a row. The pipeline holds the split its last batch read open and
+reads the next group from it while the forward-only readers can still reach
+the group's first record; otherwise (another split, the epoch wrapping onto
+the split it holds, a resumed state) it opens the split afresh and drops the
+one it held. Each open reads and verifies the split's files once.
 
 With decode="device" and a ``sharding`` (the batch's ``NamedSharding``, rows
 over the mesh axis ``data`` as ``fit`` builds it), the batch is made on the
@@ -34,14 +39,15 @@ from __future__ import annotations
 
 import queue
 import threading
-from dataclasses import dataclass
-from typing import Dict, Iterator, Optional
+from dataclasses import dataclass, replace
+from typing import Dict, Iterator, Optional, Tuple
 
 import numpy as np
 
 from ..core import trace
 from ..core.blockcache import BlockCache
 from ..core.cif import ScanStats
+from ..core.colfile import ReadCounters
 from ..core.placement import Placement
 from .sampler import SamplerState, ShardedSampler
 from .tokens import TokenCorpus, TokenSplit, check_decode, chips_decode_batch
@@ -92,17 +98,18 @@ class HostPipeline:
             raise ValueError(f"batches on the chips need decode='device', not {decode!r}")
         self.sharding = sharding
         self._layout = None  # ``labels_and_mask`` jitted for ``sharding``, on the first batch
-        # decoded-block reuse now lives in the SHARED block cache (the same
-        # policy + counters the serving path uses) instead of the ad-hoc
-        # oldest-first open-split map earlier revisions kept: splits open
-        # per batch group, and their dict pages / mask blocks come back as
-        # cache hits.  Pass the serving engine's cache to pool hot blocks
-        # across training and serving; ``stats`` folds every retired
-        # reader's counters (cache reuse included).
+        # decoded blocks of a split opened again (the epoch wrap, another
+        # pipeline, the serving path) come back from the SHARED block cache;
+        # pass the serving engine's cache to pool hot blocks across training
+        # and serving.  ``stats`` holds every reader's counters, the held
+        # split's included, as of the last batch (cache reuse included).
         self.cache = cache if cache is not None else BlockCache(
             self.DEFAULT_CACHE_BYTES
         )
         self.stats = ScanStats()
+        # the split the last batch read, held open for the batches after it:
+        # (split id, TokenSplit, each reader's counters as last folded)
+        self._held: Optional[Tuple[int, TokenSplit, Dict[str, ReadCounters]]] = None
         ids = corpus.split_ids()
         # size the corpus from split metadata only — opening every split
         # would read every column file on every host (anti-CPP startup scan)
@@ -121,14 +128,33 @@ class HostPipeline:
     # -- core synchronous iteration ----------------------------------------
     DEFAULT_CACHE_BYTES = 64 << 20
 
-    def _retire(self, sp: TokenSplit) -> None:
-        """Fold a batch group's reader counters into ``stats`` before the
-        split object is dropped (its decoded state lives on in the cache)."""
+    def _split(self, sid: int, first: int) -> Tuple[TokenSplit, int]:
+        """Split ``sid`` to read from record ``first`` on, and the number of
+        splits opened for it: the held split while its forward-only readers
+        can still reach ``first``, else ``sid`` opened afresh and held in its
+        place. An open charges its files' bytes to ``stats``, once."""
+        if self._held is not None:
+            held, sp, _ = self._held
+            if held == sid and first >= sp.position:
+                return sp, 0
+        sp = self.corpus.open_split(sid, cache=self.cache)
         for r in sp.reader.readers.values():
-            self.stats.absorb(r.counters, r.file_bytes)
+            self.stats.bytes_io += r.file_bytes
+            self.stats.files_opened += 1
+        self._held = (sid, sp, {c: ReadCounters() for c in sp.reader.readers})
+        return sp, 1
+
+    def _fold(self) -> None:
+        """Fold into ``stats`` what the held split's readers counted since
+        the last fold."""
+        _, sp, seen = self._held
+        for c, r in sp.reader.readers.items():
+            now = replace(r.counters)
+            self.stats.absorb_since(now, seen[c])
+            seen[c] = now
 
     def _make_batch(self) -> Dict[str, np.ndarray]:
-        with trace.span("feed.batch", batch=self._made):
+        with trace.span("feed.batch", batch=self._made) as span:
             self._made += 1
             it = iter(self.sampler)
             draws = [next(it) for _ in range(self.batch)]
@@ -136,20 +162,22 @@ class HostPipeline:
             for slot, (sid, rid) in enumerate(draws):
                 by_split.setdefault(sid, []).append((rid, slot))
             groups = []
+            opened = 0
             for sid, rid_slots in by_split.items():
                 # sorted ids keep the forward-only monotone readers happy; the
-                # whole group decodes in one record_batch call.  Each group
-                # opens its split fresh — the shared block cache (not held-open
-                # readers) carries the decoded dict page + mask blocks across
-                # batches, so a reopen costs file reads, not decodes.
+                # whole group decodes in one record_batch call, from the held
+                # split when its readers have not passed the group's first id.
+                # A batch that runs into the next split finishes the one held.
                 rid_slots.sort()
                 uniq = sorted({r for r, _ in rid_slots})
-                sp = self.corpus.open_split(sid, cache=self.cache)
+                sp, n = self._split(sid, uniq[0])
+                opened += n
                 if self.sharding is not None:
                     groups.append((rid_slots, uniq, sp.read_packed(uniq)))
                 else:
                     groups.append((rid_slots, uniq, *sp.record_batch(uniq, decode=self.decode)))
-                self._retire(sp)
+                self._fold()
+            span.set_metadata(opened=opened)
             if self.sharding is not None:
                 return self._on_chips(groups)
             with trace.span("feed.assemble"):

@@ -273,11 +273,14 @@ def test_pipeline_device_decode_matches_np(corpus):
 
 
 def test_pipeline_shared_block_cache_reuse(corpus):
-    # the old ad-hoc open-split map is gone: decoded-block reuse rides the
-    # shared BlockCache, stays within its byte budget, and is metered
+    # a split opened again comes back from the shared BlockCache, which stays
+    # within its byte budget and is metered.  The pipeline holds a split open
+    # for all the batches that read it, so a split is opened again only once
+    # the sampler comes back to it: draw one batch past an epoch, into the
+    # next epoch's first split
     pipe = HostPipeline(corpus, batch_per_host=4, prefetch=0, seed=1)
     it = iter(pipe)
-    for _ in range(12):
+    for _ in range(sum(corpus.split_sizes().values()) // 4 + 1):
         next(it)
         assert pipe.cache.current_bytes <= pipe.cache.capacity_bytes
     assert pipe.cache.hits > 0  # revisited splits reuse decoded blocks

@@ -86,7 +86,9 @@ def test_installed_tracer_records_the_same_spans(corpus):
     spans = [(name, args) for ph, name, _ts, _dur, _tid, args, _cat, _d in tr.events()
              if ph == "X"]
     assert FEED_SPANS <= {n for n, _ in spans}
-    assert [a for n, a in spans if n == "feed.batch"] == [{"batch": 0}, {"batch": 1}]
+    # the first batch opens its split; the second reads on from the held one
+    assert [a for n, a in spans if n == "feed.batch"] == [{"batch": 0, "opened": 1},
+                                                          {"batch": 1, "opened": 0}]
     reads = [a for n, a in spans if n == "storage.read"]
     assert reads and all(set(a) == {"column", "bytes"} and a["bytes"] > 0 for a in reads)
     assert {a["column"] for a in reads} == {"tokens", "n_tokens", "loss_mask"}
